@@ -12,7 +12,8 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * roofline rows (if dry-run artifacts exist): derived = dominant-term
     seconds per step.
 
-Scale knob: REPRO_BENCH_SCALE (default 0.5 — CPU container).
+Scale knob: REPRO_BENCH_SCALE (default 0.5). Run on the CPU, every row is
+a host-clock CPU timing, never a device measurement.
 Section filter: REPRO_BENCH_SECTIONS, a comma list of
 ``kernels,stream,tables,scaling,fig3,roofline`` (default: all). CI's bench
 job runs ``kernels,stream`` at tiny scale and diffs against the committed

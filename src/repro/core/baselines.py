@@ -1,6 +1,8 @@
 """Baseline algorithms the paper compares against (SS6.2).
 
 * Forgy K-means  (Algorithm 1)  — full-data Lloyd from a uniform-random seed.
+* K-means++ K-means             — full-data Lloyd from greedy K-means++ seeds;
+  the plain reference ``chip_smoke.py`` holds the streaming engine to.
 * PBK-BDC        (Algorithm 2)  — partition X into segments of size p,
   K-means each, pool the centroids, K-means the pool, final assign.
 * Minibatch K-means (Sculley 2010, paper SS2) — per-batch SGD centroid update
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import kmeans as km  # module import (package does not re-export the fn)
+from repro.core import kmeanspp
 from repro.kernels import ops
 
 Array = jax.Array
@@ -27,6 +30,7 @@ Array = jax.Array
 # keyed the compile cache on a fresh lambda identity, re-tracing every call.
 _jit_kmeans = jax.jit(km.kmeans, static_argnames=("max_iters", "tol", "impl"))
 _jit_objective = jax.jit(ops.mssc_objective, static_argnames=("impl",))
+_jit_kmeanspp = jax.jit(kmeanspp.kmeanspp, static_argnames=("k",))
 
 
 class BaselineResult(NamedTuple):
@@ -58,6 +62,24 @@ def forgy_kmeans(
     res = _jit_kmeans(
         jnp.asarray(x, jnp.float32), c0, max_iters=max_iters, tol=tol, impl=impl
     )
+    return BaselineResult(
+        np.asarray(res.centroids), float(res.objective), int(res.iterations)
+    )
+
+
+def kmeanspp_kmeans(
+    x: np.ndarray | Array,
+    k: int,
+    *,
+    seed: int = 0,
+    max_iters: int = 300,
+    tol: float = 1e-4,
+    impl: str | None = None,
+) -> BaselineResult:
+    """Greedy K-means++ seeds drawn on all of x, then Lloyd to convergence."""
+    xd = jnp.asarray(x, jnp.float32)
+    c0 = _jit_kmeanspp(jax.random.PRNGKey(seed), xd, k)
+    res = _jit_kmeans(xd, c0, max_iters=max_iters, tol=tol, impl=impl)
     return BaselineResult(
         np.asarray(res.centroids), float(res.objective), int(res.iterations)
     )

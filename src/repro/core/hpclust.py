@@ -10,6 +10,7 @@ across windows. More rounds / more windows can only improve the incumbent
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Iterable, Iterator, NamedTuple
 
 import jax
@@ -334,8 +335,6 @@ _jit_run_from_state_donated = jax.jit(
 def stream_from_generator(
     gen: Iterator[np.ndarray], max_windows: int
 ) -> Iterable[np.ndarray]:
-    """Utility: cap an infinite generator at max_windows windows."""
-    for i, w in enumerate(gen):
-        if i >= max_windows:
-            return
-        yield w
+    """Utility: cap an infinite generator at max_windows windows (without
+    drawing a window past the cap)."""
+    return itertools.islice(gen, max_windows)

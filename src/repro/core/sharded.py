@@ -33,10 +33,10 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.strategies import HPClustConfig
+from repro.kernels import ref
 from repro.obs import jaxhooks
 
 Array = jax.Array
@@ -78,8 +78,7 @@ def _owner_mask(value: Array, axes, sizes: dict, *, select_min: bool) -> Array:
     """Boolean: is this device('s group) the unique arg-extremum over axes?
 
     Ties broken towards the lowest flat axis index, so exactly one group
-    wins. ``sizes`` carries the static mesh axis sizes (older jax has no
-    ``lax.axis_size``; the mesh is static anyway).
+    wins. ``sizes`` carries the static mesh axis sizes.
     """
     best = jax.lax.pmin(value, axes) if select_min else jax.lax.pmax(value, axes)
     cand = value <= best if select_min else value >= best
@@ -176,20 +175,6 @@ def _reseed_degenerate_sharded(
     return cc
 
 
-def _assign_local(x: Array, c: Array):
-    """Local nearest-centroid assignment (s_loc, k) — inner-parallel tier."""
-    xf, cf = x.astype(jnp.float32), c.astype(jnp.float32)
-    d2 = (
-        jnp.sum(xf * xf, axis=1, keepdims=True)
-        - 2.0 * xf @ cf.T
-        + jnp.sum(cf * cf, axis=1)[None, :]
-    )
-    d2 = jnp.maximum(d2, 0.0)
-    idx = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    dist = jnp.min(d2, axis=1)
-    return idx, dist
-
-
 def _lloyd_sharded(
     x: Array, c0: Array, cfg: HPClustConfig, inner_axis: str
 ):
@@ -197,10 +182,12 @@ def _lloyd_sharded(
     k = cfg.k
 
     def one(c):
-        idx, dist = _assign_local(x, c)
-        onehot = jax.nn.one_hot(idx, k, dtype=jnp.float32)
-        sums = jax.lax.psum(onehot.T @ x.astype(jnp.float32), inner_axis)
-        counts = jax.lax.psum(jnp.sum(onehot, axis=0), inner_axis)
+        # Local assignment and sums on this shard (f32 products, as in the
+        # reference), then summed over the inner-parallel tier.
+        idx, dist = ref.assign_ref(x, c)
+        sums, counts = ref.cluster_sums_ref(x, idx, k)
+        sums = jax.lax.psum(sums, inner_axis)
+        counts = jax.lax.psum(counts, inner_axis)
         obj = jax.lax.psum(jnp.sum(dist), inner_axis)
         new_c = jnp.where(
             (counts == 0)[:, None], c, sums / jnp.maximum(counts, 1.0)[:, None]
@@ -413,12 +400,12 @@ def build_sharded_runner(
         pod_axis=pod_axis,
         sizes=dict(mesh.shape),
     )
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(state_specs) + (specs["reservoir"],),
         out_specs=tuple(state_specs) + (P(None, worker_axes),),
-        check_rep=False,
+        check_vma=False,
     )
 
     def fn(state: ShardedState, reservoir: Array):
@@ -489,11 +476,16 @@ def revive(state: ShardedState, groups=None) -> ShardedState:
     return state._replace(alive=jnp.asarray(alive))
 
 
-def best_of(state: ShardedState) -> tuple[Array, Array]:
+def best_of(state: ShardedState) -> tuple[np.ndarray, float]:
     """Centroids/objective of the best *live* worker group (dead and
-    non-finite incumbents are masked out of the argmin)."""
-    obj = jnp.where(
-        state.alive & jnp.isfinite(state.best_obj), state.best_obj, jnp.inf
-    )
-    w = jnp.argmin(obj)
-    return state.centroids[w], obj[w]
+    non-finite incumbents are masked out of the argmin).
+
+    Gathers to the host first, so it holds for a state on a mesh of any
+    axis type (traced indexing of an ``Explicit``-sharded array is refused).
+    """
+    st = jax.device_get(state)
+    best = np.asarray(st.best_obj, np.float32)
+    obj = np.where(np.asarray(st.alive, bool) & np.isfinite(best), best,
+                   np.inf)
+    w = int(np.argmin(obj))
+    return np.asarray(st.centroids[w]), float(obj[w])

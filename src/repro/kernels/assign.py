@@ -50,6 +50,7 @@ def _assign_kernel(
     nk: int,
     nd: int,
     bk: int,
+    precision,
 ):
     ki = pl.program_id(1)
     di = pl.program_id(2)
@@ -63,6 +64,7 @@ def _assign_kernel(
         x_ref[...],
         c_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
 
@@ -118,7 +120,8 @@ def assign_pallas(
 
     ``compute_dtype="bf16"`` feeds the MXU bf16 point/centroid tiles (half
     the VMEM traffic) while norms and the distance accumulator stay f32 —
-    the dot itself always uses ``preferred_element_type=f32``.
+    the dot itself always uses ``preferred_element_type=f32``. With f32
+    tiles the dot runs at ``HIGHEST`` precision, so it is f32 throughout.
     """
     s, d = x.shape
     k, d2 = c.shape
@@ -138,10 +141,13 @@ def assign_pallas(
         cn = jnp.where(pad_mask, jnp.inf, cn)
     if compute_dtype == "bf16":
         xk, ck = xf.astype(jnp.bfloat16), cf.astype(jnp.bfloat16)
+        precision = None
     else:
-        xk, ck = xf, cf
+        # Mosaic's default for an f32 product is one bf16 pass.
+        xk, ck, precision = xf, cf, jax.lax.Precision.HIGHEST
 
-    kernel = functools.partial(_assign_kernel, nk=nk, nd=nd, bk=bk)
+    kernel = functools.partial(_assign_kernel, nk=nk, nd=nd, bk=bk,
+                               precision=precision)
     idx, dist = pl.pallas_call(
         kernel,
         grid=(ns, nk, nd),

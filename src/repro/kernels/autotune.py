@@ -205,20 +205,24 @@ def probe(
     cands: Iterable[tuple[int, int, int]],
     *,
     reps: int = 3,
-) -> tuple[tuple[int, int, int], float]:
-    """Time ``make_call(blocks)()`` for each candidate; return (winner, us).
+) -> Optional[tuple[tuple[int, int, int], float]]:
+    """Time ``make_call(blocks)()`` for each candidate; return (winner, us),
+    or None when no candidate ran.
 
     One un-timed warmup per candidate swallows compilation; the score is the
-    median of ``reps`` timed calls. Candidates that fail to build/run (e.g.
-    an over-budget tile the estimate missed) are skipped.
+    median of ``reps`` timed calls. Candidates that fail to compile or run
+    (e.g. an over-budget tile the estimate missed) are skipped; an error
+    from ``make_call`` itself propagates.
     """
     import jax
 
     best: Optional[tuple[int, int, int]] = None
     best_us = float("inf")
     for blocks in cands:
+        # Outside the try: a factory refuses to probe off the TPU, and that
+        # must reach the caller rather than read as "no tile compiled".
+        call = make_call(blocks)
         try:
-            call = make_call(blocks)
             jax.block_until_ready(call())  # warmup / compile
             ts = []
             for _ in range(reps):
@@ -226,13 +230,14 @@ def probe(
                 jax.block_until_ready(call())
                 ts.append((time.perf_counter() - t0) * 1e6)
             us = statistics.median(ts)
-        except Exception:  # noqa: BLE001 — a broken tile is just not a winner
+        # The factories run compiled kernels on the TPU only, so what this
+        # skips is a tile the chip's compiler refuses (more VMEM or a layout
+        # the estimate missed) or that fails at run time: not a winner.
+        except Exception:  # noqa: BLE001
             continue
         if us < best_us:
             best, best_us = blocks, us
-    if best is None:
-        raise RuntimeError("no autotune candidate succeeded")
-    return best, best_us
+    return None if best is None else (best, best_us)
 
 
 # Per-kernel probe-call factories are registered by ops.py (it owns the
@@ -279,10 +284,9 @@ def lookup(
     cands = candidates(kernel, sb, kb, db, dtype=dtype)
     if not cands:
         return None
-    try:
-        blocks, us = probe(
-            lambda b: factory(sb, kb, db, dtype, b), cands)
-    except RuntimeError:
+    winner = probe(lambda b: factory(sb, kb, db, dtype, b), cands)
+    if winner is None:
         return None
+    blocks, us = winner
     _store(path, key, blocks, us)
     return blocks

@@ -39,6 +39,7 @@ def _lloyd_kernel(
     k_total: int,
     bs: int,
     s_valid: int,
+    precision,
 ):
     si = pl.program_id(0)
     ki = pl.program_id(1)
@@ -48,7 +49,7 @@ def _lloyd_kernel(
     xn = jnp.sum(xf * xf, axis=1, keepdims=True)  # (bs, 1) — norms in f32
     dots = jax.lax.dot_general(
         x, c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=precision, preferred_element_type=jnp.float32,
     )  # (bs, bk) — bf16 inputs still accumulate in f32
     d2 = jnp.maximum(xn - 2.0 * dots + cn_ref[...], 0.0)
     local_min = jnp.min(d2, axis=1, keepdims=True)
@@ -86,7 +87,7 @@ def _lloyd_kernel(
         onehot = ((winners == kk) & live).astype(x.dtype)  # (bs, K)
         sums_ref[...] += jax.lax.dot_general(
             onehot, x, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=precision, preferred_element_type=jnp.float32,
         )
         # Counts reduce in f32: a bf16 running count saturates at 256.
         counts_ref[...] += jnp.sum(
@@ -129,12 +130,15 @@ def lloyd_pass_pallas(
         cn = jnp.where(jnp.arange(k)[None, :] >= k_valid, jnp.inf, cn)
     if compute_dtype == "bf16":
         xk, ck = x.astype(jnp.bfloat16), cf.astype(jnp.bfloat16)
+        precision = None
     else:
+        # Mosaic's default for an f32 product is one bf16 pass.
         xk, ck = x.astype(jnp.float32), cf
+        precision = jax.lax.Precision.HIGHEST
 
     kernel = functools.partial(
         _lloyd_kernel, nk=nk, bk=bk, k_total=k, bs=bs,
-        s_valid=s_valid if s_valid is not None else s,
+        s_valid=s_valid if s_valid is not None else s, precision=precision,
     )
     idx, dist, sums, counts = pl.pallas_call(
         kernel,

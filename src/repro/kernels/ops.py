@@ -252,6 +252,16 @@ def lloyd_pass(
 # ---------------------------------------------------------------------------
 
 
+def _require_tpu() -> None:
+    """Probes time compiled TPU kernels only: an interpret-mode timing says
+    nothing about tiles on the chip and must never enter the cache."""
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"REPRO_AUTOTUNE=probe times compiled TPU kernels; the JAX "
+            f"backend is {backend!r}")
+
+
 def _probe_data(s: int, d: int, k: int):
     x = (jnp.arange(s * d, dtype=jnp.float32) % 97).reshape(s, d) * 0.1
     c = (jnp.arange(k * d, dtype=jnp.float32) % 89).reshape(k, d) * 0.1
@@ -259,37 +269,37 @@ def _probe_data(s: int, d: int, k: int):
 
 
 def _probe_assign(s, k, d, dtype, blocks):
+    _require_tpu()
     bs, bk, bd = blocks
     sp, kp, dp = _round_up(s, bs), _round_up(k, bk), _round_up(d, bd)
     x, c = _probe_data(sp, dp, kp)
-    interpret = jax.default_backend() != "tpu"
     return lambda: assign_pallas(
         x, c, k_valid=k, block_s=bs, block_k=bk, block_d=bd,
-        compute_dtype=dtype, interpret=interpret,
+        compute_dtype=dtype,
     )
 
 
 def _probe_update(s, k, d, dtype, blocks):
+    _require_tpu()
     bs, bk, bd = blocks
     sp, dp = _round_up(s, bs), _round_up(d, bd)
     x, _ = _probe_data(sp, dp, 1)
     idx = (jnp.arange(sp, dtype=jnp.int32) % max(k, 1))
-    interpret = jax.default_backend() != "tpu"
     return lambda: cluster_sums_pallas(
-        x, idx, k, block_s=bs, block_k=bk, block_d=bd, interpret=interpret,
+        x, idx, k, block_s=bs, block_k=bk, block_d=bd,
     )
 
 
 def _probe_lloyd(s, k, d, dtype, blocks):
     from repro.kernels.lloyd import lloyd_pass_pallas
 
+    _require_tpu()
     bs, bk, _ = blocks
     sp, kp, dp = _round_up(s, bs), _round_up(k, bk), _round_up(d, _LANE)
     x, c = _probe_data(sp, dp, kp)
-    interpret = jax.default_backend() != "tpu"
     return lambda: lloyd_pass_pallas(
         x, c, k_valid=k, s_valid=s, block_s=bs, block_k=bk,
-        compute_dtype=dtype, interpret=interpret,
+        compute_dtype=dtype,
     )
 
 

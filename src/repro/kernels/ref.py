@@ -11,19 +11,21 @@ import jax
 import jax.numpy as jnp
 
 Array = jax.Array
+_F32 = jax.lax.Precision.HIGHEST  # f32 products on a TPU, not one bf16 pass
 
 
 def pairwise_sq_dists(x: Array, c: Array) -> Array:
     """Squared Euclidean distances between rows of x (s,d) and c (k,d) -> (s,k).
 
-    Uses the expanded form ||x||^2 - 2 x.c + ||c||^2 with f32 accumulation,
-    clamped at zero (the expansion can go slightly negative in floating point).
+    Uses the expanded form ||x||^2 - 2 x.c + ||c||^2 in f32, clamped at zero
+    (the expansion can go slightly negative in floating point). The product
+    asks for ``HIGHEST`` precision: a TPU's default is one bf16 pass.
     """
     x = x.astype(jnp.float32)
     c = c.astype(jnp.float32)
     xx = jnp.sum(x * x, axis=-1, keepdims=True)  # (s, 1)
     cc = jnp.sum(c * c, axis=-1)  # (k,)
-    d2 = xx - 2.0 * (x @ c.T) + cc[None, :]
+    d2 = xx - 2.0 * jnp.matmul(x, c.T, precision=_F32) + cc[None, :]
     return jnp.maximum(d2, 0.0)
 
 
@@ -70,7 +72,7 @@ def cluster_sums_ref(x: Array, idx: Array, k: int) -> tuple[Array, Array]:
       counts: (k,)  f32 per-cluster point counts.
     """
     onehot = jax.nn.one_hot(idx, k, dtype=jnp.float32)  # (s, k)
-    sums = onehot.T @ x.astype(jnp.float32)
+    sums = jnp.matmul(onehot.T, x.astype(jnp.float32), precision=_F32)
     counts = jnp.sum(onehot, axis=0)
     return sums, counts
 

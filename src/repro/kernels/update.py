@@ -49,11 +49,13 @@ def _update_kernel(
     kk = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
     onehot = (ids == kk).astype(jnp.float32)  # (bs, bk)
 
-    # (bk, bs) x (bs, bd) on the MXU.
+    # (bk, bs) x (bs, bd) on the MXU, in f32: Mosaic's default for an f32
+    # product is one bf16 pass, which would round x.
     sums_ref[...] += jax.lax.dot_general(
         onehot,
         x_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 

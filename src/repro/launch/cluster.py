@@ -13,6 +13,7 @@ from repro import obs
 from repro.core import HPClust, HPClustConfig
 from repro.core.hpclust import stream_from_generator
 from repro.data import blob_stream
+from repro.launch.entry import device_info, enable_compile_cache
 
 
 def main(argv=None):
@@ -42,6 +43,7 @@ def main(argv=None):
                          "`python -m repro.obs summarize PATH`)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.trace:
         obs.configure(jsonl=args.trace)
     try:
@@ -83,10 +85,9 @@ def _main_stream(args):
         "sanitized_rows": res.stats.sanitized_rows if res.stats else None,
         "resumed_at": res.stats.resumed_at if res.stats else None,
         "wall_s": round(dt, 2),
+        "device": device_info(),
     }, indent=1))
     return 0
-
-
 
 
 def _main_sharded(args):
@@ -128,6 +129,7 @@ def _main_sharded(args):
         "recoveries": res.recoveries,
         "resumed_at": res.resumed_at,
         "wall_s": round(time.time() - t0, 2),
+        "device": device_info(),
     }, indent=1))
     return 0
 

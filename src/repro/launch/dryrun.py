@@ -85,16 +85,6 @@ def collective_bytes(hlo_text: str) -> dict[str, int]:
     return out
 
 
-def _cost_dict(compiled) -> dict:
-    """`Compiled.cost_analysis()` returns a dict on newer jax but a
-    list of per-program dicts on older releases (e.g. 0.4.x); normalise
-    to one flat dict either way."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _mem_dict(compiled) -> dict:
     try:
         ma = compiled.memory_analysis()
@@ -232,7 +222,7 @@ def _compile_cost(arch: str, shape: str, mesh, cfg_v) -> dict:
     _, fn, args_, _sh = build_cell(arch, shape, mesh, cfg=cfg_v)
     with mesh:
         compiled = fn.lower(*args_).compile()
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -317,7 +307,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, out_dir: Path,
         t_compile = time.time()
     rec["lower_s"] = round(t_lower - t0, 2)
     rec["compile_s"] = round(t_compile - t_lower, 2)
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     rec["cost"] = {
         "flops": float(ca.get("flops", -1.0)),
         "bytes_accessed": float(ca.get("bytes accessed", -1.0)),
@@ -398,7 +388,7 @@ def run_hpclust_cell(*, multi_pod: bool, out_dir: Path,
         lowered = jfn.lower(state, reservoir)
         compiled = lowered.compile()
     hlo = compiled.as_text()
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     name = "hpclust-prod-opt" if optimized else "hpclust-prod"
     rec = {
         "arch": name, "shape": f"k25_s131072_w{workers}",

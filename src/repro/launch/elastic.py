@@ -317,16 +317,10 @@ def run_elastic_sharded(
         raise ValueError("empty stream: nothing to cluster")
 
     st_h = to_host(state)
-    obj = np.where(
-        np.asarray(st_h.alive, bool)
-        & np.isfinite(np.asarray(st_h.best_obj, np.float32)),
-        np.asarray(st_h.best_obj, np.float32),
-        np.inf,
-    )
-    w = int(np.argmin(obj))
+    centroids, objective = sharded.best_of(st_h)
     return ElasticResult(
-        centroids=np.asarray(st_h.centroids[w]),
-        objective=float(obj[w]),
+        centroids=centroids,
+        objective=objective,
         state=st_h,
         history=history,
         windows_done=windows_done,

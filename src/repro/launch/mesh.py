@@ -12,23 +12,14 @@ from __future__ import annotations
 import math
 
 import jax
-
-try:  # jax >= 0.5 annotates axes; older versions have no AxisType at all
-    from jax.sharding import AxisType
-
-    _AXIS_TYPES = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-    _AXIS_TYPES = False
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes, devices=None):
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if _AXIS_TYPES:
-        kw["axis_types"] = (AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+    """``jax.make_mesh`` with ``Auto`` axes: its default is ``Explicit``,
+    under which the engine's traced indexing of sharded arrays is refused."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

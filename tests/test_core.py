@@ -16,7 +16,12 @@ from repro.core import HPClust, HPClustConfig, best_of
 from repro.core import kmeans as km
 from repro.core import kmeanspp as kpp
 from repro.core import strategies as strat
-from repro.core.baselines import forgy_kmeans, minibatch_kmeans, pbk_bdc
+from repro.core.baselines import (
+    forgy_kmeans,
+    kmeanspp_kmeans,
+    minibatch_kmeans,
+    pbk_bdc,
+)
 from repro.core.hpclust import stream_from_generator
 from repro.data import blob_stream
 from repro.kernels import ref
@@ -213,3 +218,23 @@ def test_baselines_sane(blobs):
     for r in (f, p, m):
         assert np.isfinite(r.objective)
         assert r.centroids.shape == (5, 8)
+
+
+def test_kmeanspp_kmeans_finds_the_blobs(blobs):
+    """K-means++ seeds one centroid per tight blob, so Lloyd lands at the
+    optimum: about m * d * sigma^2 = 6000 * 8 * 0.25."""
+    r = kmeanspp_kmeans(blobs, 5, seed=0, impl="ref")
+    assert r.centroids.shape == (5, 8)
+    assert r.objective < 1.2 * len(blobs) * 8 * 0.25
+
+
+def test_stream_from_generator_draws_no_window_past_the_cap():
+    drawn = []
+
+    def gen():
+        while True:
+            drawn.append(len(drawn))
+            yield np.zeros((4, 2), np.float32)
+
+    assert len(list(stream_from_generator(gen(), 3))) == 3
+    assert drawn == [0, 1, 2]

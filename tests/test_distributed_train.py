@@ -21,12 +21,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.distributed import sharding as shd
 from repro.launch import steps as S
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 
-# small real mesh: 4-way DP x 2-way TP (axis_types defaults to Auto, and
-# the kwarg does not exist on older jax)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+# small real mesh: 4-way DP x 2-way TP. Built with Auto axes: a bare
+# jax.make_mesh defaults to Explicit, which the model's sharding rules reject.
+mesh = make_host_mesh((4, 2))
 cfg = get_config("qwen3-0.6b", smoke=True)
 
 p_shard = shd.param_shardings(cfg, mesh)
@@ -82,16 +82,17 @@ import json, sys
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
+from repro.launch.mesh import make_host_mesh
 
 mgr = CheckpointManager("%s")
 tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
 if "%s" == "save":
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_host_mesh((len(jax.devices()),), ("data",))
     sh = NamedSharding(mesh, P("data", None))
     mgr.save(3, {"w": jax.device_put(tree["w"], sh)})
     print(json.dumps({"saved": True}))
 else:
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_host_mesh((len(jax.devices()),), ("data",))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     step, out = mgr.restore(tree, shardings=sh)
     print(json.dumps({

@@ -26,6 +26,8 @@ def test_cluster_driver():
     rec = json.loads(out[out.index("{"):])
     assert rec["sample_objective"] > 0
     assert rec["rounds_total"] == 6
+    # The record names the device it ran on: a run off the chip shows it.
+    assert rec["device"]["platform"] == "cpu" and rec["device"]["count"] >= 1
 
 
 def test_train_driver_loss_improves():
@@ -57,3 +59,4 @@ def test_cluster_driver_sharded_engine():
     rec = json.loads(out[out.index("{"):])
     assert rec["engine"] == "shard_map"
     assert rec["monotone"] is True
+    assert rec["device"]["platform"] == "cpu"

@@ -275,8 +275,9 @@ import jax.numpy as jnp
 from repro.core import sharded
 from repro.core.strategies import HPClustConfig
 from repro.resilience.chaos import poison_worker_group
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh((4, 2))
 cfg = HPClustConfig(k=4, sample_size=64, workers=4, rounds=4,
                     strategy="hybrid", fixed_schedule=True, kmeans_iters=8,
                     groups=2)
@@ -307,8 +308,9 @@ import jax.numpy as jnp
 from repro.core import sharded
 from repro.core.strategies import HPClustConfig
 from repro.resilience.chaos import desync_pod
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = HPClustConfig(k=4, sample_size=32, workers=4, rounds=6,
                     strategy="hybrid2", fixed_schedule=True, kmeans_iters=8,
                     groups=2, sync_every=2)

@@ -21,8 +21,9 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.strategies import HPClustConfig
 from repro.core import sharded
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh((4, 2))
 cfg = HPClustConfig(k=5, sample_size=64, workers=4, rounds=6,
                     strategy="%s", fixed_schedule=True, kmeans_iters=16,
                     groups=2)
@@ -67,8 +68,9 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.strategies import HPClustConfig
 from repro.core import sharded
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = HPClustConfig(k=4, sample_size=32, workers=4, rounds=6,
                     strategy="hybrid2", fixed_schedule=True, kmeans_iters=8,
                     groups=2, sync_every=2)
@@ -107,8 +109,6 @@ def test_dryrun_cell_compiles_on_host_mesh():
     with mesh:
         compiled = fn.lower(*args).compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns per-program dicts
-        ca = ca[0] if ca else {}
     assert ca.get("flops", 0) > 1e12
 
 

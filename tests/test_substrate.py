@@ -198,7 +198,6 @@ def test_error_feedback_unbiased_over_time():
 
 
 def test_compressed_psum_matches_psum():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_host_mesh
@@ -210,8 +209,8 @@ def test_compressed_psum_matches_psum():
         out, _ = comp.compressed_psum(xs, "data", comp.ef_init(xs.shape))
         return out
 
-    y = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                  check_rep=False)(x)
+    y = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                      check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x), rtol=2e-2, atol=2e-2)
 
 

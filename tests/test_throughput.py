@@ -23,6 +23,7 @@ from repro.core import strategies
 from repro.data import device_stream
 from repro.data.pipeline import blob_stream
 from repro.kernels import autotune, ops
+from repro.kernels.assign import assign_pallas
 
 CFG = HPClustConfig(k=4, sample_size=256, workers=2, rounds=3)
 
@@ -202,6 +203,17 @@ def test_autotune_probe_persists_and_results_stay_exact(monkeypatch, tmp_path):
     ref_idx, ref_d2 = ops.assign_clusters(
         jnp.asarray(x), jnp.asarray(c), impl="ref")
 
+    # The registered factories time compiled TPU kernels only; stand in an
+    # interpret-mode factory so the probe -> persist -> lookup path runs here.
+    def interpret_probe(s, k, d, dtype, blocks):
+        bs, bk, bd = blocks
+        xp, cp = ops._probe_data(ops._round_up(s, bs), ops._round_up(d, bd),
+                                 ops._round_up(k, bk))
+        return lambda: assign_pallas(
+            xp, cp, k_valid=k, block_s=bs, block_k=bk, block_d=bd,
+            compute_dtype=dtype, interpret=True)
+
+    monkeypatch.setitem(autotune._PROBE_FACTORIES, "assign", interpret_probe)
     monkeypatch.setenv("REPRO_AUTOTUNE", "probe")
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
     autotune.invalidate_memory_cache()
@@ -218,6 +230,24 @@ def test_autotune_probe_persists_and_results_stay_exact(monkeypatch, tmp_path):
         [(key, entry)] = [(k, v) for k, v in blob["entries"].items()
                           if "/assign/" in k]
         assert len(entry["blocks"]) == 3 and entry["us"] > 0
+    finally:
+        autotune.invalidate_memory_cache()
+
+
+def test_autotune_probe_refuses_off_tpu(monkeypatch, tmp_path):
+    """The registered probes never time interpret mode into the cache."""
+    path = tmp_path / "autotune.json"
+    # A shape no other test compiles: tiles are chosen at trace time.
+    x = np.asarray(_windows(1, m=299, d=20)[0])
+    c = np.asarray(np.random.default_rng(2).normal(size=(5, 20)), np.float32)
+    monkeypatch.setenv("REPRO_AUTOTUNE", "probe")
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
+    autotune.invalidate_memory_cache()
+    try:
+        with pytest.raises(RuntimeError, match="backend is 'cpu'"):
+            ops.assign_clusters(jnp.asarray(x), jnp.asarray(c),
+                                impl="interpret")
+        assert not path.exists()
     finally:
         autotune.invalidate_memory_cache()
 
