@@ -30,13 +30,15 @@ Array = jax.Array
 
 def _emit_round_metrics(metrics: RoundMetrics, *, window: int | None = None) -> None:
     """Publish per-round competition telemetry (objective descent, accepted
-    rounds, quarantines) as ``hpclust.round`` trace events. No-op (and no
-    device->host transfer) when tracing is disabled."""
+    rounds, Lloyd iterations, quarantines) as ``hpclust.round`` trace events
+    and counters. ``metrics`` holds host values already fetched from the
+    device: this never waits on it. No-op when tracing is disabled."""
     rec = obs.get_recorder()
     if rec is None:
         return
     best = np.asarray(metrics.best_obj)        # (rounds, W)
     accepted = np.asarray(metrics.accepted)
+    iters = np.asarray(metrics.kmeans_iters)
     quarantined = np.asarray(metrics.quarantined)
     w = best.shape[1] if best.ndim == 2 else 1
     for r in range(best.shape[0]):
@@ -46,9 +48,11 @@ def _emit_round_metrics(metrics: RoundMetrics, *, window: int | None = None) -> 
             window=window,
             best_obj=float(best[r].min()),
             accepted=f"{int(accepted[r].sum())}/{w}",
+            lloyd_iters=iters[r].tolist(),
             quarantined=int(quarantined[r].sum()),
         )
     rec.inc("hpclust.rounds", int(best.shape[0]))
+    rec.inc("hpclust.lloyd_iters", int(iters.sum()))
     n_quar = int(quarantined.sum())
     if n_quar:
         rec.inc("resilience.quarantined_workers", n_quar)
@@ -94,7 +98,8 @@ class HPClust:
                       strategy=self.config.strategy, k=self.config.k,
                       workers=self.config.workers):
             state, metrics = _jit_run_hpclust(key, data, cfg=self.config)
-            _emit_round_metrics(metrics)
+            if obs.enabled():
+                _emit_round_metrics(jax.device_get(metrics))
         c, obj = strategies.best_of(state)
         return HPClustResult(
             centroids=np.asarray(c),
@@ -212,6 +217,8 @@ class HPClust:
                     snapshot = None
                     if donate and ckpt is not None:
                         snapshot = jax.device_get(state)
+                    # Dispatch only: the program runs on while the host
+                    # goes on to the one per-window fetch below.
                     with obs.span("hpclust.rounds", rounds=run_cfg.rounds):
                         try:
                             state, metrics = run_fn(state, data, cfg=run_cfg)
@@ -219,8 +226,11 @@ class HPClust:
                             if snapshot is not None:
                                 state = snapshot
                             raise
-                        _emit_round_metrics(metrics, window=wi)
-                    hist.append(np.asarray(metrics.best_obj))
+                    with obs.span("stream.sync"):
+                        if obs.enabled():
+                            metrics = jax.device_get(metrics)
+                        hist.append(np.asarray(metrics.best_obj))
+                    _emit_round_metrics(metrics, window=wi)
                     windows_done = wi + 1
                     obs.inc("stream.windows")
                     obs.inc("stream.rows", int(data.shape[0]))

@@ -139,19 +139,22 @@ def _worker_round(
 ):
     """One HPClust round for one worker (Algorithm 3 lines 7-18)."""
     key, k_seed = jax.random.split(key)
-    seeded = kmeanspp.reseed_degenerate(
-        k_seed, sample, base_c, base_deg, n_candidates=cfg.n_candidates
-    )
-    if cfg.fixed_schedule:
-        res = km.kmeans_fixed(
-            sample, seeded, iters=min(cfg.kmeans_iters, 64), tol=cfg.kmeans_tol,
-            impl=cfg.impl,
+    # The sharded engine's scope names: a profile reads both engines alike.
+    with jaxhooks.named_scope("round.reseed"):
+        seeded = kmeanspp.reseed_degenerate(
+            k_seed, sample, base_c, base_deg, n_candidates=cfg.n_candidates
         )
-    else:
-        res = km.kmeans(
-            sample, seeded, max_iters=cfg.kmeans_iters, tol=cfg.kmeans_tol,
-            impl=cfg.impl,
-        )
+    with jaxhooks.named_scope("round.lloyd"):
+        if cfg.fixed_schedule:
+            res = km.kmeans_fixed(
+                sample, seeded, iters=min(cfg.kmeans_iters, 64),
+                tol=cfg.kmeans_tol, impl=cfg.impl,
+            )
+        else:
+            res = km.kmeans(
+                sample, seeded, max_iters=cfg.kmeans_iters,
+                tol=cfg.kmeans_tol, impl=cfg.impl,
+            )
     # A non-finite candidate objective (corrupt sample, degenerate math) can
     # never displace the incumbent — -inf would otherwise "win" the compare.
     accept = (res.objective < state_obj) & jnp.isfinite(res.objective)

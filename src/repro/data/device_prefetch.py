@@ -16,15 +16,17 @@ and all-bad windows — so prefetch on/off cannot change results, only their
 arrival time. Producer exceptions are re-raised in the consumer as the
 ORIGINAL exception object (the chaos suites assert on exception types).
 
-Observability: ``prefetch.depth`` (ready windows in the queue) and
-``prefetch.overlap_s`` (host prepare seconds hidden behind device compute
-for each window) gauges, when a ``repro.obs`` recorder is active.
+Observability, when a ``repro.obs`` recorder is active: ``sanitize.window``
+and ``h2d.put`` spans around the two host stages of each window (on the
+producer thread), and ``stream.wait`` around the consumer's wait for its
+next window (on the synchronous path, around the whole preparation).
+``h2d.put`` covers what ``place`` holds the host for; an asynchronous
+``device_put`` returns before its copy ends.
 """
 from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Callable, Iterable, Iterator, NamedTuple, Optional
 
 import jax
@@ -79,7 +81,9 @@ def _prepare(
         if w is None:  # every row non-finite: the caller skips + counts it
             return PrefetchedWindow(wi, None, None, n_bad, flagged)
     w = np.asarray(w, np.float32)
-    return PrefetchedWindow(wi, w, place(w), n_bad, flagged)
+    with obs.span("h2d.put"):
+        placed = place(w)
+    return PrefetchedWindow(wi, w, placed, n_bad, flagged)
 
 
 def device_stream(
@@ -115,7 +119,9 @@ def device_stream(
             if wi < start_at:
                 continue
             flagged = bool(flag_fn()) if flag_fn is not None else False
-            yield _prepare(wi, window, sanitize, place, flagged)
+            with obs.span("stream.wait", window=wi):
+                item = _prepare(wi, window, sanitize, place, flagged)
+            yield item
             if flagged:
                 return
         return
@@ -141,9 +147,7 @@ def device_stream(
                 if wi < start_at:
                     continue
                 flagged = bool(flag_fn()) if flag_fn is not None else False
-                t0 = time.perf_counter()
-                item = _prepare(wi, window, sanitize, place, flagged)
-                _put((item, time.perf_counter() - t0))
+                _put(_prepare(wi, window, sanitize, place, flagged))
                 if flagged:
                     break
             _put(_Done())
@@ -155,31 +159,25 @@ def device_stream(
     t.start()
     try:
         while True:
-            waited = 0.0
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    got = q.get(timeout=_POLL_S)
-                    waited += time.perf_counter() - t0
-                    break
-                except queue.Empty:
-                    waited += time.perf_counter() - t0
-                    if not t.is_alive() and q.empty():
-                        raise RuntimeError(
-                            "device prefetch thread died without reporting "
-                            "an error"
-                        ) from None
+            # The last wait is for the end of the stream: it carries no
+            # ``window``.
+            with obs.span("stream.wait") as wait:
+                while True:
+                    try:
+                        got = q.get(timeout=_POLL_S)
+                        break
+                    except queue.Empty:
+                        if not t.is_alive() and q.empty():
+                            raise RuntimeError(
+                                "device prefetch thread died without "
+                                "reporting an error"
+                            ) from None
+                if isinstance(got, PrefetchedWindow):
+                    wait.set(window=got.index)
             if isinstance(got, _Done):
                 return
             if isinstance(got, _Failure):
                 raise got.exc  # the original exception, type preserved
-            item, prep_s = got
-            rec = obs.get_recorder()
-            if rec is not None:
-                rec.gauge("prefetch.depth", q.qsize())
-                # Host prepare time hidden behind device compute: what the
-                # consumer did NOT have to wait for.
-                rec.gauge("prefetch.overlap_s", max(0.0, prep_s - waited))
-            yield item
+            yield got
     finally:
         stop.set()

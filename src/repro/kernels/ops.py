@@ -18,15 +18,9 @@ static heuristics in ``_heuristic_blocks`` otherwise. ``compute_dtype``
 to bf16 inputs with f32 accumulation; it is a *static* jit argument so each
 dtype gets its own compile-cache entry.
 
-Observability: each public wrapper opens a host-side ``kernel.*`` span when a
-``repro.obs`` recorder is active AND the call is a real dispatch (arguments
-are concrete, not tracers — inside an enclosing jit the wrapper runs at
-trace time, where host timing is meaningless). The jitted bodies carry
-``jax.named_scope`` labels so the regions survive into HLO metadata and XLA
-profiles regardless. Dispatch is asynchronous, so a kernel span measures
-dispatch cost unless the recorder was configured with ``sync_kernels=True``
-(then the span blocks on the result — true execution time, at the price of a
-pipeline bubble).
+Observability: the jitted bodies carry ``jax.named_scope`` labels
+(``kernel.assign``, ``kernel.update``, ...), so each kernel's ops are named
+in HLO metadata and XLA profiles, inside the round program too.
 """
 from __future__ import annotations
 
@@ -35,7 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import flags, obs
+from repro import flags
 from repro.kernels import autotune, ref
 from repro.kernels.assign import assign_pallas
 from repro.kernels.update import cluster_sums_pallas
@@ -93,21 +87,6 @@ def _blocks(kernel: str, s: int, k: int, d: int,
     return _round_up(bs, sub), _round_up(bk, _LANE), _round_up(bd, _LANE)
 
 
-def _traced_call(rec, name: str, attrs: dict, thunk):
-    """One host-side kernel span around a dispatch. The span covers dispatch
-    only (async) unless the recorder asks for ``sync_kernels`` — then it
-    blocks on the result and covers execution."""
-    with rec.span(name, **attrs), jaxhooks.trace_annotation(name):
-        out = thunk()
-        if rec.sync_kernels:
-            jax.block_until_ready(out)
-    return out
-
-
-def _is_concrete(x) -> bool:
-    return not isinstance(x, jax.core.Tracer)
-
-
 @functools.partial(jax.jit, static_argnames=("impl", "compute_dtype"))
 def _assign_clusters_jit(
     x: Array, c: Array, *, impl: str | None = None, compute_dtype: str = "f32",
@@ -135,13 +114,7 @@ def assign_clusters(
 ) -> tuple[Array, Array]:
     """Nearest-centroid assignment: x (s,d), c (k,d) -> (idx (s,), dist (s,))."""
     cdt = flags.compute_dtype(compute_dtype)
-    rec = obs.get_recorder()
-    if rec is None or not _is_concrete(x):
-        return _assign_clusters_jit(x, c, impl=impl, compute_dtype=cdt)
-    return _traced_call(
-        rec, "kernel.assign", {"s": int(x.shape[0]), "k": int(c.shape[0])},
-        lambda: _assign_clusters_jit(x, c, impl=impl, compute_dtype=cdt),
-    )
+    return _assign_clusters_jit(x, c, impl=impl, compute_dtype=cdt)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "impl"))
@@ -166,13 +139,7 @@ def _cluster_sums_jit(x: Array, idx: Array, k: int, *, impl: str | None = None) 
 
 def cluster_sums(x: Array, idx: Array, k: int, *, impl: str | None = None) -> tuple[Array, Array]:
     """Per-cluster sums (k,d) and counts (k,) from assignments idx (s,)."""
-    rec = obs.get_recorder()
-    if rec is None or not _is_concrete(x):
-        return _cluster_sums_jit(x, idx, k, impl=impl)
-    return _traced_call(
-        rec, "kernel.update", {"s": int(x.shape[0]), "k": k},
-        lambda: _cluster_sums_jit(x, idx, k, impl=impl),
-    )
+    return _cluster_sums_jit(x, idx, k, impl=impl)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "compute_dtype"))
@@ -190,13 +157,7 @@ def mssc_objective(
 ) -> Array:
     """Equation (1): sum of squared distances to nearest centroids."""
     cdt = flags.compute_dtype(compute_dtype)
-    rec = obs.get_recorder()
-    if rec is None or not _is_concrete(x):
-        return _mssc_objective_jit(x, c, impl=impl, compute_dtype=cdt)
-    return _traced_call(
-        rec, "kernel.objective", {"s": int(x.shape[0]), "k": int(c.shape[0])},
-        lambda: _mssc_objective_jit(x, c, impl=impl, compute_dtype=cdt),
-    )
+    return _mssc_objective_jit(x, c, impl=impl, compute_dtype=cdt)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "compute_dtype"))
@@ -237,13 +198,7 @@ def lloyd_pass(
     D exceeds the VMEM row-block budget.
     """
     cdt = flags.compute_dtype(compute_dtype)
-    rec = obs.get_recorder()
-    if rec is None or not _is_concrete(x):
-        return _lloyd_pass_jit(x, c, impl=impl, compute_dtype=cdt)
-    return _traced_call(
-        rec, "kernel.lloyd_pass", {"s": int(x.shape[0]), "k": int(c.shape[0])},
-        lambda: _lloyd_pass_jit(x, c, impl=impl, compute_dtype=cdt),
-    )
+    return _lloyd_pass_jit(x, c, impl=impl, compute_dtype=cdt)
 
 
 # ---------------------------------------------------------------------------

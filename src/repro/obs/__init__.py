@@ -19,8 +19,9 @@ Typical use (what the launch CLIs' ``--trace`` flag does)::
     obs.shutdown()               # metrics snapshot + close sinks
 
 Read the trace back with ``python -m repro.obs summarize trace.jsonl``.
-Device-side naming (``jax.named_scope``/``TraceAnnotation``/profiler
-sessions/device memory) lives in ``repro.obs.jaxhooks``.
+Device-side naming (``jax.named_scope``, and the ``TraceAnnotation`` that
+mirrors a configured recorder's spans into a ``jax.profiler`` trace) lives
+in ``repro.obs.jaxhooks``.
 """
 from __future__ import annotations
 
@@ -68,14 +69,21 @@ def configure(
     jsonl: str | None = None,
     sinks: tuple = (),
     clock: Clock = time.monotonic,
-    sync_kernels: bool = False,
 ) -> Recorder:
     """Build a ``Recorder`` (JSONL sink when ``jsonl`` is given, plus any
-    extra ``sinks``), install it, and return it."""
+    extra ``sinks``), install it, and return it.
+
+    Its spans are mirrored as ``jax.profiler.TraceAnnotation``s of the same
+    name: inside an active profiler session they land in the trace's host
+    plane, on the device ops' clock; outside one they cost next to
+    nothing."""
+    from repro.obs import jaxhooks  # jax only once a recorder is wanted
+
     all_sinks = list(sinks)
     if jsonl is not None:
         all_sinks.append(JsonlSink(jsonl))
-    rec = Recorder(tuple(all_sinks), clock=clock, sync_kernels=sync_kernels)
+    rec = Recorder(tuple(all_sinks), clock=clock,
+                   annotate=jaxhooks.trace_annotation)
     set_recorder(rec)
     return rec
 

@@ -60,7 +60,8 @@ class Span:
     without any caller bookkeeping."""
 
     __slots__ = (
-        "name", "attrs", "span_id", "parent_id", "t0", "dur", "thread", "_rec"
+        "name", "attrs", "span_id", "parent_id", "t0", "dur", "thread", "_rec",
+        "_mirror",
     )
 
     def __init__(self, rec: "Recorder", name: str, attrs: dict):
@@ -72,6 +73,7 @@ class Span:
         self.dur = 0.0
         self.thread = threading.current_thread().name
         self._rec = rec
+        self._mirror = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -81,11 +83,17 @@ class Span:
         stack = self._rec._stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        if self._rec.annotate is not None:
+            self._mirror = self._rec.annotate(self.name)
+            self._mirror.__enter__()
         self.t0 = self._rec.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur = self._rec.clock() - self.t0
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+            self._mirror = None
         stack = self._rec._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -250,10 +258,12 @@ class Recorder:
     close; metrics accumulate in the registry and are emitted as one
     ``{"type": "metrics"}`` snapshot record on ``flush()``/``close()``.
 
-    ``sync_kernels=True`` makes the kernel-dispatch spans in
-    ``repro.kernels.ops`` block until the device result is ready, trading a
-    pipeline bubble for true execution timing (off by default — async
-    dispatch means a kernel span normally measures dispatch cost only).
+    ``annotate`` mirrors every span into another timeline: called with the
+    span's name, it returns a context manager entered and exited with the
+    span on the same thread. ``repro.obs.configure()`` passes
+    ``jaxhooks.trace_annotation``, so spans land in an active
+    ``jax.profiler`` trace on the device ops' clock; a bare ``Recorder``
+    mirrors nothing.
     """
 
     def __init__(
@@ -261,12 +271,12 @@ class Recorder:
         sinks: tuple = (),
         *,
         clock: Clock = time.monotonic,
-        sync_kernels: bool = False,
+        annotate: Optional[Callable[[str], Any]] = None,
     ):
         self.clock = clock
         self.sinks = list(sinks)
         self.metrics = MetricRegistry()
-        self.sync_kernels = sync_kernels
+        self.annotate = annotate
         # Span ids are only unique within one recorder; the run token keys
         # them globally so appended traces from several CLI invocations (or
         # several recorders in one test process) never cross-link.

@@ -3,25 +3,26 @@
 Everything here degrades to a no-op when jax (or the specific profiler API)
 is unavailable, so importing this module never adds a hard dependency beyond
 what the instrumented code already has. Host-side spans live in
-``repro.obs.core``; these hooks cover the *device* side:
+``repro.obs.core``; these hooks connect them to JAX's profiler:
 
   * ``named_scope``      — names a traced region so it survives into HLO
     metadata and XLA profiles (usable inside jit/vmap/scan bodies);
   * ``trace_annotation`` — host-thread annotation visible in a
     ``jax.profiler`` timeline (NOT usable inside traced code);
-  * ``profiler_session`` — wrap a region in a jax.profiler trace dump;
-  * ``device_memory_stats`` / ``sample_device_memory`` — per-device memory
-    gauges where the backend exposes them (TPU does; CPU returns nothing).
+    ``repro.obs.configure()`` mirrors every span through it.
 """
 from __future__ import annotations
 
 import contextlib
-from typing import ContextManager, Iterator
+from typing import ContextManager
 
 try:  # pragma: no cover - exercised implicitly by every traced test
     import jax
 except Exception:  # noqa: BLE001 — analysis-only hosts may lack jax entirely
     jax = None  # type: ignore[assignment]
+
+_TRACE_ANNOTATION = getattr(getattr(jax, "profiler", None),
+                            "TraceAnnotation", None)
 
 
 def named_scope(name: str) -> ContextManager:
@@ -32,56 +33,8 @@ def named_scope(name: str) -> ContextManager:
 
 
 def trace_annotation(name: str) -> ContextManager:
-    """``jax.profiler.TraceAnnotation`` when available, else a null context."""
-    prof = getattr(jax, "profiler", None) if jax is not None else None
-    cls = getattr(prof, "TraceAnnotation", None)
-    if cls is not None:
-        return cls(name)
+    """``jax.profiler.TraceAnnotation`` when available, else a null context.
+    Outside a profiler session the annotation records nothing."""
+    if _TRACE_ANNOTATION is not None:
+        return _TRACE_ANNOTATION(name)
     return contextlib.nullcontext()
-
-
-@contextlib.contextmanager
-def profiler_session(logdir: str) -> Iterator[None]:
-    """Run a ``jax.profiler`` trace around the with-body, dumping to
-    ``logdir`` (TensorBoard/XProf format). No-op without the API."""
-    prof = getattr(jax, "profiler", None) if jax is not None else None
-    if prof is None or not hasattr(prof, "start_trace"):
-        yield
-        return
-    prof.start_trace(logdir)
-    try:
-        yield
-    finally:
-        prof.stop_trace()
-
-
-def device_memory_stats() -> dict[str, dict[str, int]]:
-    """Per-device ``memory_stats()`` where the backend exposes it.
-
-    Returns ``{device: {stat: bytes}}``; empty on backends without the API
-    (host CPU) — callers must treat absence as "unknown", not zero.
-    """
-    if jax is None:
-        return {}
-    out: dict[str, dict[str, int]] = {}
-    for dev in jax.local_devices():
-        try:
-            stats = dev.memory_stats()
-        except Exception:  # noqa: BLE001 — per-device API is best-effort
-            stats = None
-        if stats:
-            out[str(dev)] = {
-                k: int(v) for k, v in stats.items()
-                if isinstance(v, (int, float))
-            }
-    return out
-
-
-def sample_device_memory(recorder) -> None:
-    """Record ``bytes_in_use`` per device as gauges on ``recorder``."""
-    if recorder is None:
-        return
-    for dev, stats in device_memory_stats().items():
-        used = stats.get("bytes_in_use")
-        if used is not None:
-            recorder.gauge(f"device.bytes_in_use.{dev}", used)
