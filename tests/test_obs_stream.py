@@ -1,11 +1,13 @@
 """The streaming path's spans, scopes and counters: ``stream.wait``,
-``h2d.put``, ``sanitize.window`` and ``stream.sync`` once per window,
+``h2d.put``, ``sanitize.window`` (with its screen's ``threads`` and
+``blocks``) and ``stream.sync`` once per window,
 program spans mirrored into a ``jax.profiler`` trace, the ``round.reseed`` /
 ``round.lloyd`` scopes in the round program, and the Lloyd-iteration
 counter against the program's own ``RoundMetrics``."""
 from __future__ import annotations
 
 import contextlib
+import os
 import re
 import sys
 from pathlib import Path
@@ -16,7 +18,9 @@ import pytest
 
 from repro import obs
 from repro.core import HPClust, HPClustConfig, hpclust, strategies
+from repro.data.device_prefetch import device_stream
 from repro.obs import jaxhooks
+from repro.resilience.sanitize import screen_plan
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -53,6 +57,9 @@ def test_stream_spans_once_per_window(configured, depth):
     for name in ("sanitize.window", "h2d.put", "stream.sync",
                  "stream.window", "hpclust.rounds"):
         assert len(_spans(sink, name)) == WINDOWS, name
+    # Tiny windows are screened inline, in one row block.
+    assert all(s["attrs"] == {"threads": 1, "blocks": 1}
+               for s in _spans(sink, "sanitize.window"))
     waits = _spans(sink, "stream.wait")
     assert [w["attrs"]["window"] for w in waits
             if "window" in w["attrs"]] == list(range(WINDOWS))
@@ -68,6 +75,21 @@ def test_stream_spans_once_per_window(configured, depth):
         by_id = {r["span_id"]: r for r in sink.records if r["type"] == "span"}
         assert all(by_id[s["parent_id"]]["name"] == "stream.wait"
                    for s in _spans(sink, "h2d.put"))
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_sanitize_span_reports_the_screen_plan(configured, depth):
+    _, sink = configured
+    tiny = _windows(1)[0]
+    rows = screen_plan((1, 768)).rows
+    big = _windows(1, rows=3 * rows + 5, d=768)[0]
+    items = list(device_stream([tiny, big], depth=depth, place=lambda w: w))
+    assert [it.n_bad for it in items] == [0, 0]
+    tiny_span, big_span = _spans(sink, "sanitize.window")
+    assert tiny_span["attrs"] == {"threads": 1, "blocks": 1}
+    assert big_span["attrs"]["blocks"] == 4
+    if len(os.sched_getaffinity(0)) > 1:
+        assert big_span["attrs"]["threads"] > 1
 
 
 def test_stream_sync_follows_dispatch_inside_the_window(configured):

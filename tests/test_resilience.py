@@ -10,6 +10,9 @@ Run separately from tier-1 (CI job: chaos):
     PYTHONPATH=src JAX_PLATFORMS=cpu pytest tests/test_resilience.py -q
 """
 import itertools
+import os
+import sys
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +35,7 @@ from repro.resilience import (
 )
 from repro.resilience import chaos
 from repro.resilience.chaos import ChaosError
+from repro.resilience.sanitize import screen_plan
 
 
 # ---------------------------------------------------------------------------
@@ -134,6 +138,108 @@ def test_sanitize_window_all_bad_and_bad_rank():
     assert out is None and n_bad == 4
     with pytest.raises(ValueError):
         sanitize_window(np.zeros((4,), np.float32))
+
+
+def _sanitize_oracle(x):
+    """``sanitize_window`` as first written: one whole-window
+    ``isfinite(x).all(axis=1)``, the reference for the blocked screen."""
+    x = np.asarray(x)
+    bad = ~np.isfinite(x).all(axis=1)
+    n_bad = int(bad.sum())
+    if n_bad == 0:
+        return x, 0
+    good_idx = np.flatnonzero(~bad)
+    if good_idx.size == 0:
+        return None, n_bad
+    out = np.array(x, copy=True)
+    fill = good_idx[np.arange(n_bad) % good_idx.size]
+    out[np.flatnonzero(bad)] = x[fill]
+    return out, n_bad
+
+
+def _poison(x, rows, seed=0):
+    """Put NaN, +Inf and -Inf, in turn, at a random column of each row."""
+    rng = np.random.default_rng(seed)
+    for i, r in enumerate(rows):
+        x[r, rng.integers(x.shape[1])] = (np.nan, np.inf, -np.inf)[i % 3]
+    return x
+
+
+def _window(m, d, dtype=np.float32, seed=0):
+    return np.random.default_rng(seed).normal(size=(m, d)).astype(dtype)
+
+
+def _pooled(d=768, dtype=np.float32, seed=0):
+    """A window of more than one row block, so the pool runs: three full
+    row blocks and a short fourth, with bad values on the first and last row
+    of each block and of the window, and in random rows."""
+    rows = screen_plan((1, d)).rows
+    m = 3 * rows + 5
+    x = _window(m, d, dtype, seed)
+    edges = [0, rows - 1, rows, 2 * rows - 1, 2 * rows, 3 * rows - 1,
+             3 * rows, m - 1]
+    rand = np.random.default_rng(seed + 1).choice(m, 40, replace=False)
+    return _poison(x, sorted(set(edges) | set(rand.tolist())), seed)
+
+
+_SANITIZE_CASES = {
+    "small_nan_inf": lambda: _poison(_window(200, 7), [0, 3, 50, 51, 199]),
+    "pooled_block_edges": _pooled,
+    "pooled_clean": lambda: _window(3 * screen_plan((1, 768)).rows + 5, 768),
+    "small_clean": lambda: _window(64, 5),
+    "all_bad_small": lambda: np.full((4, 3), np.nan, np.float32),
+    "pooled_all_bad": lambda: _poison(
+        _window(4 * screen_plan((1, 512)).rows, 512),
+        range(4 * screen_plan((1, 512)).rows)),
+    "single_row_clean": lambda: _window(1, 768),
+    "single_row_bad": lambda: _poison(_window(1, 768), [0]),
+    "pooled_sliced": lambda: _pooled(d=770)[::2, 1:-1],
+    "sliced_small": lambda: _poison(_window(40, 9), [1, 2, 39])[1::3, ::2],
+    "pooled_float64": lambda: _pooled(dtype=np.float64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SANITIZE_CASES))
+def test_sanitize_window_bit_identical_to_whole_window_screen(case):
+    x = _SANITIZE_CASES[case]()
+    if case.startswith("pooled") and len(os.sched_getaffinity(0)) > 1:
+        assert screen_plan(x.shape).threads > 1
+    want, want_bad = _sanitize_oracle(x)
+    out, n_bad = sanitize_window(x)
+    assert n_bad == want_bad
+    if want is None:
+        assert out is None
+        return
+    if want_bad == 0:
+        assert out is x  # no copy on the common path
+        return
+    assert out.dtype == want.dtype and out.shape == want.shape
+    assert out.tobytes() == want.tobytes()
+
+
+def test_sanitize_window_concurrent_callers_share_the_pool():
+    windows = [_pooled(d=64, seed=s) for s in range(8)]
+    want = [_sanitize_oracle(x) for x in windows]
+    got = [None] * len(windows)
+
+    def work(i):
+        got[i] = sanitize_window(windows[i])
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(len(windows))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(prev)
+    for (out, n_bad), (ref, ref_bad) in zip(got, want):
+        assert n_bad == ref_bad > 0
+        assert out.tobytes() == ref.tobytes()
 
 
 def test_stream_sanitization_counts_and_keeps_centroids_finite():

@@ -134,6 +134,50 @@ def test_device_stream_reraises_original_exception():
         list(device_stream(gen(), depth=2))
 
 
+class _Copy:
+    """What a placement returns: a copy that lands when waited on."""
+
+    def __init__(self, i, log, fails):
+        self.i, self.log, self.fails = i, log, fails
+
+    def block_until_ready(self):
+        self.log.append(("landed", self.i))
+        if self.fails:  # left for the consumer, not raised here
+            raise jax.errors.JaxRuntimeError("copy failed")
+        return self
+
+
+def _logging_place(log, fails=False):
+    def place(w):
+        i = len([e for e in log if e[0] == "put"])
+        log.append(("put", i))
+        return _Copy(i, log, fails)
+    return place
+
+
+@pytest.mark.parametrize("copy_fails", [False, True])
+def test_device_stream_starts_a_copy_once_the_last_has_landed(
+        monkeypatch, copy_fails):
+    from repro.data import device_prefetch
+
+    log = []
+    monkeypatch.setattr(device_prefetch, "default_place",
+                        _logging_place(log, copy_fails))
+    got = list(device_stream(iter(_windows(3)), depth=2))
+    assert [i.device.i for i in got] == [0, 1, 2]
+    assert log == [("put", 0), ("landed", 0), ("put", 1), ("landed", 1),
+                   ("put", 2)]
+
+
+def test_device_stream_leaves_a_given_placement_unsettled():
+    # The sharded tier passes its own ``place``; its copies are not waited on.
+    log = []
+    got = list(device_stream(iter(_windows(3)), depth=2,
+                             place=_logging_place(log)))
+    assert [i.device.i for i in got] == [0, 1, 2]
+    assert log == [("put", 0), ("put", 1), ("put", 2)]
+
+
 def test_device_stream_flags_in_pull_order_and_stops():
     pulls = {"n": 0}
     wins = _windows(5)
