@@ -30,9 +30,10 @@ Array = jax.Array
 
 def _emit_round_metrics(metrics: RoundMetrics, *, window: int | None = None) -> None:
     """Publish per-round competition telemetry (objective descent, accepted
-    rounds, Lloyd iterations, quarantines) as ``hpclust.round`` trace events
-    and counters. ``metrics`` holds host values already fetched from the
-    device: this never waits on it. No-op when tracing is disabled."""
+    rounds, Lloyd iterations, redrawn degenerate rows, quarantines) as
+    ``hpclust.round`` trace events and counters. ``metrics`` holds host
+    values already fetched from the device: this never waits on it. No-op
+    when tracing is disabled."""
     rec = obs.get_recorder()
     if rec is None:
         return
@@ -40,6 +41,7 @@ def _emit_round_metrics(metrics: RoundMetrics, *, window: int | None = None) -> 
     accepted = np.asarray(metrics.accepted)
     iters = np.asarray(metrics.kmeans_iters)
     quarantined = np.asarray(metrics.quarantined)
+    reseed = np.asarray(metrics.reseed_rows)
     w = best.shape[1] if best.ndim == 2 else 1
     for r in range(best.shape[0]):
         rec.event(
@@ -49,10 +51,13 @@ def _emit_round_metrics(metrics: RoundMetrics, *, window: int | None = None) -> 
             best_obj=float(best[r].min()),
             accepted=f"{int(accepted[r].sum())}/{w}",
             lloyd_iters=iters[r].tolist(),
+            reseed_rows=reseed[r].tolist(),
             quarantined=int(quarantined[r].sum()),
         )
     rec.inc("hpclust.rounds", int(best.shape[0]))
     rec.inc("hpclust.lloyd_iters", int(iters.sum()))
+    rec.inc("hpclust.reseed_rounds", int((reseed.sum(axis=-1) > 0).sum()))
+    rec.inc("hpclust.reseed_rows", int(reseed.sum()))
     n_quar = int(quarantined.sum())
     if n_quar:
         rec.inc("resilience.quarantined_workers", n_quar)

@@ -26,6 +26,7 @@ Strategies:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -83,6 +84,7 @@ class RoundMetrics(NamedTuple):
     accepted: Array      # (W,) bool — did the round improve the incumbent
     kmeans_iters: Array  # (W,) int32
     quarantined: Array   # (W,) bool — poisoned incumbent re-seeded this round
+    reseed_rows: Array   # (W,) int32 — degenerate warm-start rows redrawn
 
 
 def _mask_nonfinite(obj: Array) -> Array:
@@ -100,7 +102,7 @@ def quarantine_nonfinite(state: WorkerState) -> tuple[WorkerState, Array]:
     healthiest (finite-argmin) survivor's centroids and degenerate mask and
     resetting its objective to +inf — so its next round warm-starts from the
     survivor (degenerate rows re-drawn by ``kmeanspp.reseed_degenerate`` in
-    ``_worker_round``) and any finite result is accepted. If *every* worker
+    ``_reseed``) and any finite result is accepted. If *every* worker
     is poisoned, all reset to the virgin all-degenerate state and the search
     re-seeds from scratch, exactly like round 0.
     """
@@ -127,23 +129,35 @@ def init_state(key: Array, cfg: HPClustConfig, d: int) -> WorkerState:
     )
 
 
+def _reseed(keys: Array, samples: Array, base_c: Array, base_deg: Array,
+            cfg: HPClustConfig) -> Array:
+    """K-means++ redraw of every worker's degenerate warm-start rows
+    (Algorithm 3 line 8), run only in a round where some worker has one.
+
+    The ``cond`` sits outside the ``vmap`` over workers: under it the
+    predicate is batched and the ``cond`` becomes a ``select`` that computes
+    the redraw's distance pass for every row of every worker in every round.
+    With no degenerate row the redraw returns its input, so passing
+    ``base_c`` through gives the same bits.
+    """
+    def redraw():
+        return jax.vmap(functools.partial(
+            kmeanspp.reseed_degenerate, n_candidates=cfg.n_candidates
+        ))(keys, samples, base_c, base_deg)
+
+    return jax.lax.cond(jnp.any(base_deg), redraw, lambda: base_c)
+
+
 def _worker_round(
     state_c: Array,
     state_obj: Array,
     state_deg: Array,
-    key: Array,
-    base_c: Array,
-    base_deg: Array,
+    seeded: Array,
     sample: Array,
     cfg: HPClustConfig,
 ):
-    """One HPClust round for one worker (Algorithm 3 lines 7-18)."""
-    key, k_seed = jax.random.split(key)
-    # The sharded engine's scope names: a profile reads both engines alike.
-    with jaxhooks.named_scope("round.reseed"):
-        seeded = kmeanspp.reseed_degenerate(
-            k_seed, sample, base_c, base_deg, n_candidates=cfg.n_candidates
-        )
+    """One HPClust round for one worker from its seeded warm start
+    (Algorithm 3 lines 9-18)."""
     with jaxhooks.named_scope("round.lloyd"):
         if cfg.fixed_schedule:
             res = km.kmeans_fixed(
@@ -161,7 +175,7 @@ def _worker_round(
     new_c = jnp.where(accept, res.centroids, state_c)
     new_obj = jnp.where(accept, res.objective, state_obj)
     new_deg = jnp.where(accept, res.counts == 0, state_deg)
-    return new_c, new_obj, new_deg, key, accept, res.iterations
+    return new_c, new_obj, new_deg, accept, res.iterations
 
 
 def _select_base(state: WorkerState, coop: Array, cfg: HPClustConfig):
@@ -248,25 +262,28 @@ def run_rounds(
                 lambda kk: jax.random.randint(kk, (cfg.sample_size,), 0, m)
             )(sample_keys)
             samples = data[idx]  # (W, s, d)
+            # The first half rides to the next round, the second seeds.
+            keys = jax.vmap(lambda kk: jax.random.split(kk))(next_keys)
+            carry_keys, seed_keys = keys[:, 0], keys[:, 1]
+        # The sharded engine's scope names: a profile reads both engines alike.
+        with jaxhooks.named_scope("round.reseed"):
+            reseed_rows = jnp.sum(base_deg, axis=1, dtype=jnp.int32)
+            seeded = _reseed(seed_keys, samples, base_c, base_deg, cfg)
         with jaxhooks.named_scope("round.worker_round"):
-            new_c, new_obj, new_deg, keys2, accepted, iters = jax.vmap(
-                lambda c, o, dg, kk, bc, bd, sm: _worker_round(
-                    c, o, dg, kk, bc, bd, sm, cfg
-                )
+            new_c, new_obj, new_deg, accepted, iters = jax.vmap(
+                lambda c, o, dg, sc, sm: _worker_round(c, o, dg, sc, sm, cfg)
             )(
                 state.centroids,
                 state.best_obj,
                 state.degenerate,
-                next_keys,
-                base_c,
-                base_deg,
+                seeded,
                 samples,
             )
-        new_state = WorkerState(new_c, new_obj, new_deg, keys2)
+        new_state = WorkerState(new_c, new_obj, new_deg, carry_keys)
         with jaxhooks.named_scope("round.cross_group_sync"):
             new_state = _cross_group_sync(new_state, r, cfg)
         return new_state, RoundMetrics(
-            new_state.best_obj, accepted, iters, quarantined
+            new_state.best_obj, accepted, iters, quarantined, reseed_rows
         )
 
     return jax.lax.scan(round_fn, state, jnp.arange(cfg.rounds))

@@ -2,8 +2,8 @@
 ``h2d.put``, ``sanitize.window`` (with its screen's ``threads`` and
 ``blocks``) and ``stream.sync`` once per window,
 program spans mirrored into a ``jax.profiler`` trace, the ``round.reseed`` /
-``round.lloyd`` scopes in the round program, and the Lloyd-iteration
-counter against the program's own ``RoundMetrics``."""
+``round.lloyd`` scopes in the round program, and the Lloyd-iteration and
+reseed counters against the program's own ``RoundMetrics``."""
 from __future__ import annotations
 
 import contextlib
@@ -158,12 +158,14 @@ def test_round_program_carries_reseed_and_lloyd_scopes():
     lowered = hpclust._jit_run_from_state.lower(state, x, cfg=CFG)
     text = lowered.as_text(debug_info=True)
     assert "round.reseed" in text and "round.lloyd" in text
-    # In the compiled program's op names the scopes sit under the round
-    # body (wrapped by the vmap over workers), and the kernel scopes stay
-    # parts of the path beneath round.lloyd.
+    # In the compiled program's op names the reseed's conditional sits in
+    # the round body outside the vmap over workers, round.lloyd under it,
+    # and the kernel scopes stay parts of the path beneath round.lloyd.
     names = set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
     worker = "round.worker_round/vmap("
-    assert any(f"{worker}round.reseed)/" in n for n in names)
+    assert any(n.endswith("/while/body/closed_call/round.reseed/cond")
+               for n in names)
+    assert not any("vmap(round.reseed)" in n for n in names)
     for kernel in ("kernel.assign", "kernel.update"):
         assert any(f"{worker}round.lloyd)/" in n and f"/{kernel}/" in n
                    for n in names), kernel
@@ -195,6 +197,29 @@ def test_lloyd_iters_match_round_metrics(configured, entry):
     np.testing.assert_array_equal(got, want)
     assert rec.metrics.counter("hpclust.lloyd_iters").snapshot() == \
         want.sum()
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_reseed_rows_match_round_metrics(configured, depth):
+    rec, sink = configured
+    xs = _windows(2)
+    HPClust(CFG, seed=3, prefetch=depth).fit_stream(xs)
+    key, k0 = jax.random.split(jax.random.PRNGKey(3))
+    state = strategies.init_state(k0, CFG, xs[0].shape[1])
+    want = []
+    for x in xs:  # fit_stream's windows, replayed without donation
+        state, m = hpclust._jit_run_from_state(state, x, cfg=CFG)
+        want.append(np.asarray(m.reseed_rows))
+    want = np.concatenate(want)             # (rounds, workers)
+    assert want[0].tolist() == [CFG.k] * CFG.workers  # the fresh start
+    events = [r["attrs"] for r in sink.records
+              if r["type"] == "event" and r["name"] == "hpclust.round"]
+    got = np.array([e["reseed_rows"] for e in events])
+    np.testing.assert_array_equal(got, want)
+    counter = rec.metrics.counter
+    assert counter("hpclust.reseed_rows").snapshot() == want.sum()
+    assert counter("hpclust.reseed_rounds").snapshot() == \
+        (want.sum(axis=1) > 0).sum()
 
 
 def test_traced_fit_stream_matches_untraced():

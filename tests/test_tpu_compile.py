@@ -101,6 +101,8 @@ def test_round_program_compiles_with_kernels_and_fits(one_chip):
     compiled = hpclust._jit_run_from_state_donated.lower(
         state, data, cfg=cfg).compile()
     assert _has_kernel(compiled)
+    # The reseed stays a conditional the chip skips, not a select of both.
+    assert " conditional(" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
